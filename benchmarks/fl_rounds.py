@@ -61,6 +61,9 @@ Three measurements seed the perf trajectory of the round hot path:
     per-process peak RSS on each side, with the 2-process run asserted
     BITWISE identical to the single-process run (losses, comm, RMSE, final
     weights) and the host store asserted to split exactly across processes.
+    This harness is CPU-only: its children run ``JAX_PLATFORMS=cpu`` (gloo
+    collectives), and the parent never touches JAX — a chip belongs to one
+    process, so the parent takes ``env`` from the children's reports.
 
   PYTHONPATH=src python -m benchmarks.fl_rounds [--quick | --multihost]
 
@@ -481,6 +484,7 @@ def _multihost_child() -> dict:
             np.asarray(hist["state"]["w_global"]).tobytes()).hexdigest(),
         "final_rmse": hist["final_rmse"],
         "comm_params": hist["final_comm"],
+        "env": record_env(),
     }))
     return {}
 
@@ -492,7 +496,8 @@ def bench_multihost(num_clients: int = 100_000, cohort: int = 256,
     single-process run (per-round losses, comm, RMSE, final weights) while
     spreading the host-resident client fleet — per-process peak RSS is the
     headline number. Both sides run in FRESH child processes so the RSS
-    readings are comparable (no inherited allocator state)."""
+    readings are comparable (no inherited allocator state). CPU-only: the
+    children run on ``JAX_PLATFORMS=cpu`` and this parent stays off JAX."""
     from repro.launch.distributed import spawn_processes
 
     env = dict(os.environ)
@@ -637,7 +642,9 @@ if __name__ == "__main__":
     if args.multihost_child:
         _multihost_child()
     elif args.multihost:
-        results = {"env": record_env(), "multihost": bench_multihost()}
+        multihost = bench_multihost()
+        results = {"env": multihost["single_process"]["env"],
+                   "multihost": multihost}
         save_json("fl_rounds", "results", results, keep_existing=True)
     else:
         run(quick=args.quick)

@@ -23,7 +23,9 @@ from benchmarks.common import csv_row, time_call
 
 
 def kernel_microbench():
-    """us_per_call for each Pallas kernel (interpret mode on CPU) vs oracle."""
+    """us_per_call for each Pallas kernel vs its oracle: compiled on the chip,
+    interpreted on the CPU backend (``repro.kernels.resolve_interpret``), so a
+    CPU row is a timing of the interpreter, never of the kernel."""
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.kernels.flash_attention.ref import attention_ref
     from repro.kernels.psgf_mix.ops import psgf_mix
@@ -36,19 +38,20 @@ def kernel_microbench():
     q = jax.random.normal(ks[0], (1, 256, 4, 64))
     k = jax.random.normal(ks[1], (1, 256, 2, 64))
     v = jax.random.normal(ks[2], (1, 256, 2, 64))
-    fa = jax.jit(lambda a, b, c: flash_attention(a, b, c, interpret=True,
-                                                 block_q=128, block_k=128))
+    fa = jax.jit(lambda a, b, c: flash_attention(a, b, c, block_q=128,
+                                                 block_k=128))
     fr = jax.jit(lambda a, b, c: attention_ref(a, b, c))
-    csv_row("flash_attention_interp", time_call(fa, q, k, v), "B1,S256,H4,hd64")
+    mode = jax.default_backend()
+    csv_row(f"flash_attention_{mode}", time_call(fa, q, k, v), "B1,S256,H4,hd64")
     csv_row("flash_attention_ref", time_call(fr, q, k, v), "oracle")
 
     D = 539_000  # LoGTST parameter-vector size
     wg = jax.random.normal(ks[3], (D,))
     wl = jax.random.normal(ks[4], (D,))
     m = jax.random.uniform(ks[0], (D,)) < 0.3
-    pm = jax.jit(lambda a, b, c: psgf_mix(a, b, c, interpret=True))
+    pm = jax.jit(psgf_mix)
     pr = jax.jit(psgf_mix_ref)
-    csv_row("psgf_mix_interp", time_call(pm, wg, wl, m), f"D={D}")
+    csv_row(f"psgf_mix_{mode}", time_call(pm, wg, wl, m), f"D={D}")
     csv_row("psgf_mix_ref", time_call(pr, wg, wl, m), "oracle")
 
     x = jax.random.normal(ks[0], (1, 128, 256))
@@ -56,9 +59,9 @@ def kernel_microbench():
     Bm = jax.random.normal(ks[2], (1, 128, 16))
     Cm = jax.random.normal(ks[3], (1, 128, 16))
     A = -jnp.exp(0.1 * jax.random.normal(ks[4], (256, 16)))
-    sk = jax.jit(lambda *a: ssm_scan(*a, interpret=True, chunk=64, d_block=128))
+    sk = jax.jit(lambda *a: ssm_scan(*a, chunk=64, d_block=128))
     sr = jax.jit(ssm_scan_ref)
-    csv_row("ssm_scan_interp", time_call(sk, x, dt, Bm, Cm, A), "S128,D256,N16")
+    csv_row(f"ssm_scan_{mode}", time_call(sk, x, dt, Bm, Cm, A), "S128,D256,N16")
     csv_row("ssm_scan_ref", time_call(sr, x, dt, Bm, Cm, A), "oracle")
 
 

@@ -15,6 +15,7 @@ import argparse
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.tasks import ExperimentSpec, get_task, run_experiment, task_forecaster
 
 
@@ -39,6 +40,7 @@ def main():
                          "in (0, 1] (FLConfig.participation); only the "
                          "sampled cohort trains/communicates each round")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.participation is not None:
         # "0.25" -> fraction of each cluster, "4" -> fixed cohort size
         args.participation = (float(args.participation)

@@ -34,7 +34,8 @@ lay the client axis out across local devices — the while driver threads those
 shardings through ``in_shardings`` on its donated carry so the one-dispatch run
 stays client-sharded end-to-end. ``FLConfig.use_pallas_mix`` routes the
 element-granularity downlink mix through the fused ``psgf_mix`` Pallas kernel
-(mix + comm count in one pass over the mask; interpret-mode fallback off-TPU).
+(mix + comm count in one pass over the mask; compiled on the chip, run in the
+interpreter only on the CPU backend).
 ``FLConfig.streaming_windows`` drops the materialized ``(K, n_win, L+T)``
 window tensors entirely: every driver carries only the raw ``(K, T)`` split
 slices and gathers minibatch/eval windows ON DEVICE inside the compiled loop
@@ -71,6 +72,7 @@ Entry points:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -124,8 +126,9 @@ class FLConfig:
     client_chunk: Optional[int] = None
     # use_pallas_mix: route the element-granularity (K, D) downlink mix through
     # the fused psgf_mix Pallas kernel (mix + comm count in ONE pass over the
-    # mask instead of separate mix_down + gate_count reductions). Falls back to
-    # interpret mode automatically off-TPU; bit-identical either way.
+    # mask instead of separate mix_down + gate_count reductions). Compiled on
+    # the chip; the CPU backend runs it in the Pallas interpreter (the only
+    # place interpret mode is allowed). Bit-identical to the jnp path.
     use_pallas_mix: bool = False
     # streaming_windows: train and evaluate straight off RAW (K, T) series
     # slices (repro.data.windowing.client_series_datasets) instead of the
@@ -314,37 +317,60 @@ def quantize_wire_vec(vec, meta, comm_bits: int, key=None):
     return out
 
 
-def mix_down_count(client_tree, global_tree, gates, *, use_pallas: bool = False,
-                   interpret: Optional[bool] = None):
+def mix_down_count(client_tree, global_tree, gates, *, use_pallas: bool = False):
     """Fused downlink: returns ``(mix_down(...), gate_count(...))``.
 
-    On the element-granularity path — ONE ``(K, D)`` leaf with dense ``(K, D)``
-    gates — ``use_pallas=True`` runs the fused ``psgf_mix`` Pallas kernel, which
-    produces the mixed matrix and the comm count in a single pass over the mask
-    (the separate ``gate_count`` reduction re-reads the whole mask otherwise).
-    ``interpret=None`` auto-selects interpret mode off-TPU. Gate sums are 0/1
+    ``use_pallas=True`` runs the fused ``psgf_mix`` Pallas kernel, which
+    produces the mixed matrix and the comm count in a single pass over the
+    mask (the separate ``gate_count`` reduction re-reads the whole mask
+    otherwise). The kernel takes the element-granularity path only — ONE
+    float32 ``(K, D)`` client leaf with dense ``(K, D)`` gates — and any other
+    tree raises instead of quietly taking the jnp path. Gate sums are 0/1
     integers, so the fused count is bit-identical to ``gate_count`` while the
     per-round total stays inside float32's exact-integer range (2^24 ~ 1.6e7
     gated params/round); beyond that both paths carry ACCOUNTING_DTYPE's
     relative error, in possibly different rounding orders (see the accounting
     note at the top of this module). The mix math is the same lerp either way.
     """
+    if not use_pallas:
+        return (mix_down(client_tree, global_tree, gates),
+                gate_count(gates, client_tree))
     cl = jax.tree_util.tree_leaves(client_tree)
     gl = jax.tree_util.tree_leaves(global_tree)
     gt = jax.tree_util.tree_leaves(gates)
-    if (use_pallas and len(cl) == 1 and len(gl) == 1 and len(gt) == 1
+    if not (len(cl) == 1 and len(gl) == 1 and len(gt) == 1
             and cl[0].ndim == 2 and gl[0].ndim == 1
             and gt[0].shape == cl[0].shape and cl[0].dtype == jnp.float32):
-        from repro.kernels.psgf_mix.ops import psgf_mix_batch
+        raise ValueError(
+            "use_pallas=True needs one float32 (K, D) client leaf with dense "
+            f"(K, D) gates; got client leaves "
+            f"{[(l.shape, str(l.dtype)) for l in cl]} and gate leaves "
+            f"{[g.shape for g in gt]}")
+    from jax.sharding import PartitionSpec as P
 
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        mixed, count = psgf_mix_batch(gl[0], cl[0], gt[0], interpret=interpret)
-        structure = jax.tree_util.tree_structure(client_tree)
-        return (jax.tree_util.tree_unflatten(structure, [mixed]),
-                count.astype(ACCOUNTING_DTYPE))
-    return (mix_down(client_tree, global_tree, gates),
-            gate_count(gates, client_tree))
+    from repro.kernels.psgf_mix.ops import psgf_mix_batch
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if "clients" in mesh.axis_names:
+        # a Pallas kernel cannot be partitioned by the compiler: under
+        # run_fl's client mesh each device mixes its own rows and the counts
+        # are summed (exact: integer-valued partial sums)
+        rows = (P("clients") if cl[0].shape[0] % mesh.shape["clients"] == 0
+                else P())
+
+        def local(g, w, m):
+            mixed, count = psgf_mix_batch(g, w, m)
+            return mixed, (jax.lax.psum(count, "clients") if rows != P()
+                           else count)
+
+        mixed, count = jax.shard_map(
+            local, mesh=mesh, in_specs=(P(), rows, rows),
+            out_specs=(rows, P()), check_vma=False)(gl[0], cl[0], gt[0])
+    else:
+        mixed, count = psgf_mix_batch(gl[0], cl[0], gt[0])
+    structure = jax.tree_util.tree_structure(client_tree)
+    return (jax.tree_util.tree_unflatten(structure, [mixed]),
+            count.astype(ACCOUNTING_DTYPE))
 
 
 def sync_round(local, global_, key, policy, select_ratio: float):
@@ -858,7 +884,9 @@ def axis0_shardings(mesh_axis: str = "clients", mesh=None):
         devices = jax.devices()
         if len(devices) <= 1:
             return None
-        mesh = jax.make_mesh((len(devices),), (mesh_axis,))
+        from repro.launch.mesh import _make_mesh
+
+        mesh = _make_mesh((len(devices),), (mesh_axis,))
     from jax.sharding import NamedSharding, PartitionSpec
 
     return (NamedSharding(mesh, PartitionSpec(mesh_axis)),
@@ -1055,7 +1083,14 @@ def run_fl(
             state = {k: jax.device_put(v, shardings[k])
                      for k, v in state.items()}
     elif shard_clients:
+        shardings = client_state_shardings(state)
         state = shard_client_state(state)
+    # drivers run under the client mesh, where mix_down_count partitions the
+    # fused downlink kernel by hand
+    mesh = None if shardings is None else next(iter(shardings.values())).mesh
+
+    def in_mesh():
+        return contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)
 
     history = {"round": [], "train_loss": [], "comm": [], "rmse": []}
     best_loss = math.inf
@@ -1066,8 +1101,9 @@ def run_fl(
     if driver == "loop":
         for r in range(max_rounds):
             key, rk = jax.random.split(key)
-            state, metrics = _round_jit(state, train_data, rk, model_cfg,
-                                        fl_cfg, meta, policy)
+            with in_mesh():
+                state, metrics = _round_jit(state, train_data, rk, model_cfg,
+                                            fl_cfg, meta, policy)
             loss = float(metrics["train_loss"])
             comm_total = float(metrics["comm_total"])
             history["round"].append(r)
@@ -1091,8 +1127,9 @@ def run_fl(
         r = 0
         while r < max_rounds and not stop:
             n = min(eval_every, max_rounds - r)
-            state, key, ms = _run_chunk(state, key, train_data, model_cfg,
-                                        fl_cfg, meta, policy, n)
+            with in_mesh():
+                state, key, ms = _run_chunk(state, key, train_data, model_cfg,
+                                            fl_cfg, meta, policy, n)
             losses = np.asarray(ms["train_loss"])   # ONE host sync per chunk
             comms = np.asarray(ms["comm_total"])
             history["round"].extend(range(r, r + n))
@@ -1117,8 +1154,6 @@ def run_fl(
                 print(f"round {r - 1:4d}  loss {losses[-1]:.4f}  "
                       f"rmse {rmse:.4f}  comm {comm_total:.3e}")
     elif driver == "while":
-        if shardings is None and shard_clients and client_mesh is None:
-            shardings = client_state_shardings(state)
         if shardings is None:
             fn = _run_while_jit
         else:
@@ -1126,7 +1161,6 @@ def run_fl(
             # via in_shardings (train_data rides along client-sharded too)
             from jax.sharding import NamedSharding, PartitionSpec
 
-            mesh = next(iter(shardings.values())).mesh
             ndev = mesh.devices.size
             data_spec = (PartitionSpec("clients")
                          if train_data.shape[0] % ndev == 0
@@ -1138,8 +1172,9 @@ def run_fl(
                          donate_argnames=("state",),
                          in_shardings=(shardings, None, data_sh, None))
         # statics ride positionally: pjit rejects kwargs with in_shardings
-        out = fn(state, key, train_data, test_data, model_cfg, fl_cfg, meta,
-                 policy, max_rounds, eval_every, patience)
+        with in_mesh():
+            out = fn(state, key, train_data, test_data, model_cfg, fl_cfg,
+                     meta, policy, max_rounds, eval_every, patience)
         state, key, loss_buf, comm_buf, rmse_buf, rounds_dev, chunks_dev = out
         if multihost:
             # gather the run-level history to every host ONCE at run end (the

@@ -42,8 +42,9 @@ class ForecastConfig:
     dropout: float = 0.0        # kept for config parity; eval-mode graphs
     revin: bool = True
     # use_flash_attn: route _self_attn through the Pallas flash-attention
-    # kernel (repro.kernels.flash_attention, bidirectional causal=False,
-    # interpret-mode fallback off-TPU). Numerics match the dense jnp path to
+    # kernel (repro.kernels.flash_attention, bidirectional causal=False;
+    # compiled on the chip, interpreted only on the CPU backend — see
+    # repro.kernels.resolve_interpret). Numerics match the dense jnp path to
     # FLASH_ATTN_TOL (guarded in tests/test_flash_forecast.py, the same
     # bit-tolerance contract psgf_mix carries); False (the default) is the
     # exact historical dense softmax, bitwise.
@@ -232,10 +233,8 @@ def _self_attn(p, x, cfg: ForecastConfig):
     if cfg.use_flash_attn:
         from repro.kernels.flash_attention.ops import flash_attention
 
-        # (B, N, H, hd) is already the kernel layout; tokens attend
-        # bidirectionally (eq. 2), so causal=False. interpret=None falls
-        # back to interpret mode off-TPU automatically.
-        o = flash_attention(q, k, v, causal=False, interpret=None)
+        # tokens attend bidirectionally (eq. 2), so causal=False
+        o = flash_attention(q, k, v, causal=False)
     else:
         s = jnp.einsum("bnhk,bmhk->bhnm", q, k) / math.sqrt(hd)
         a = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.forecaster import Forecaster, get_forecaster, save_forecaster
 from repro.core.fl.engine import FLConfig, run_fl
 from repro.data.clustering import cluster_clients
@@ -393,7 +394,8 @@ def run_experiment(spec: ExperimentSpec, checkpoint_dir: Optional[str] = None,
 
     Returns ``{"task", "model", "cluster_sizes", "rows"}`` where each row has
     ``policy`` (grid label), ``cluster`` (None when pooled), ``clients``,
-    ``rounds``, ``rmse``, ``comm_params``, ``comm_bytes`` and ``train_s``.
+    ``rounds``, ``train_loss`` (per round), ``rmse``, ``comm_params``,
+    ``comm_bytes`` and ``train_s``.
     With ``checkpoint_dir``, every trained global model is saved under
     ``<dir>/<policy>[_c<cluster>]`` in ``load_forecaster`` format and a
     routing manifest (:func:`write_routing_manifest`) indexing cluster label
@@ -436,6 +438,7 @@ def run_experiment(spec: ExperimentSpec, checkpoint_dir: Optional[str] = None,
                 "cluster": c,
                 "clients": int(tr.shape[0]),
                 "rounds": int(hist["rounds_run"]),
+                "train_loss": [float(x) for x in hist["train_loss"]],
                 "rmse": float(hist["final_rmse"]),
                 "comm_params": float(hist["final_comm"]),
                 # engine-computed wire bytes: payload at comm_bits/8 per
@@ -467,6 +470,7 @@ def main():
     ap.add_argument("--rounds", type=int, default=30)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     task = get_task(args.task, quick=args.quick, clusters=args.clusters)
     spec = ExperimentSpec(
         task=task, model=task_forecaster(task, args.model, quick=args.quick),

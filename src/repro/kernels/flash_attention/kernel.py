@@ -1,11 +1,16 @@
 """Pallas TPU flash attention (GQA, causal / sliding-window).
 
-Design (TPU-native, per DESIGN.md hardware-adaptation):
+Design (TPU-native):
   * grid = (batch, q_heads, num_q_blocks, num_kv_blocks); the kv dimension is
     innermost and sequential ("arbitrary"), carrying the online-softmax state
     (m, l, acc) in VMEM scratch across kv steps.
-  * BlockSpec tiles: q (1, block_q, 1, hd), k/v (1, block_k, 1, hd) — q tiles
-    stay resident while K/V stream HBM->VMEM block by block.
+  * The kernel takes heads-major (B, H, S, hd) operands, so each BlockSpec
+    tile is q (1, 1, block_q, hd), k/v (1, 1, block_k, hd): the last two
+    block dims are (block, hd), with block a multiple of 8 and hd the whole
+    head dim, which is what the TPU lowering accepts. A (1, block, 1, hd)
+    tile of a (B, S, H, hd) array puts a 1 against H in the second-to-last
+    dim and is refused. q tiles stay resident while K/V stream HBM->VMEM
+    block by block.
   * block sizes default to 512x512 with hd<=256: working set
     ~ (block_q + 2*block_k) * hd * 4B + block_q*block_k*4B ≈ 1.6 MB << VMEM.
   * MXU alignment: block_q/block_k multiples of 128; hd is the contraction.
@@ -14,7 +19,8 @@ Design (TPU-native, per DESIGN.md hardware-adaptation):
 
 Masking uses absolute positions (q_offset + iota), so causal and
 sliding-window are one code path. Validated against ref.py in interpret mode
-(tests/test_kernels_flash_attention.py).
+(tests/test_kernels.py) and compiled for a described v5e in
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -25,9 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax<0.5 names this TPUCompilerParams; jax>=0.5 renamed it CompilerParams
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -43,9 +46,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
+    q = q_ref[0, 0].astype(jnp.float32)  # (bq, hd)
+    k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)  # (bk, hd)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (bq, bk)
 
@@ -82,20 +85,21 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == num_kv_blocks - 1)
     def _finish():
         l = l_scr[...]
-        o_ref[0, :, 0, :] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_attention_kernel(q, k, v, *, causal=True, window=None,
                            block_q=512, block_k=512, kv_len=None,
                            interpret=False):
-    """q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd) with H % KV == 0.
+    """Heads-major layout: q (B, H, Sq, hd); k, v (B, KV, Skv, hd) with
+    H % KV == 0. Returns (B, H, Sq, hd).
 
     Sq/Skv must already be padded to block multiples (ops.py handles padding
     and unpadding); ``kv_len`` is the ORIGINAL (unpadded) kv length used to
     mask out padding keys.
     """
-    B, Sq, H, hd = q.shape
-    _, Skv, KV, _ = k.shape
+    B, H, Sq, hd = q.shape
+    _, KV, Skv, _ = k.shape
     assert H % KV == 0
     group = H // KV
     block_q = min(block_q, Sq)
@@ -115,18 +119,18 @@ def flash_attention_kernel(q, k, v, *, causal=True, window=None,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, iq, ik: (b, ik, h // group, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, iq, ik: (b, ik, h // group, 0)),
+            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, iq, ik: (b, h // group, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, iq, ik: (b, h // group, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,10 +1,10 @@
-"""Jit'd public wrapper for the flash_attention Pallas kernel: pads sequence
-lengths to block multiples, dispatches, unpads. ``interpret=True`` executes
-the kernel body in Python on CPU (how this container validates it); on real
-TPUs the same call lowers to Mosaic. ``interpret=None`` (the default) picks
-interpret mode automatically whenever the default backend is not a TPU, so
-callers like the forecaster's ``_self_attn`` can route through the kernel
-unconditionally.
+"""Jit'd public wrapper for the flash_attention Pallas kernel: moves the
+sequence-major (B, S, H, hd) operands to the kernel's heads-major layout,
+pads sequence lengths to block multiples, dispatches, unpads. On a TPU the
+call lowers to Mosaic. ``interpret=None`` (the default) resolves through
+:func:`repro.kernels.resolve_interpret`: interpret mode on the CPU backend,
+where the tests run the kernel body in Python, and the compiled kernel
+everywhere else; interpret mode is refused off the CPU.
 
 Differentiation: ``pallas_call`` has no autodiff rule, so ``flash_attention``
 carries a ``jax.custom_vjp`` whose backward pass is the VJP of the dense jnp
@@ -23,25 +23,28 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import attention_ref
 
 
 def _flash_fwd_impl(causal, window, block_q, block_k, interpret, q, k, v):
-    """pad -> kernel -> unpad (the primal pipeline)."""
-    B, Sq, H, hd = q.shape
+    """heads-major -> pad -> kernel -> unpad -> sequence-major (the primal
+    pipeline)."""
+    Sq = q.shape[1]
     Skv = k.shape[1]
     bq = min(block_q, _round_up(Sq, 128))
     bk = min(block_k, _round_up(Skv, 128))
-    pad_q = (-Sq) % bq
-    pad_k = (-Skv) % bk
-    qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))) if pad_q else q
-    kp = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else k
-    vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else v
-    out = flash_attention_kernel(qp, kp, vp, causal=causal, window=window,
-                                 block_q=bq, block_k=bk, kv_len=Skv,
-                                 interpret=interpret)
-    return out[:, :Sq]
+
+    def heads_major(x, pad):
+        x = jnp.swapaxes(x, 1, 2)   # (B, S, H, hd) -> (B, H, S, hd)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+    out = flash_attention_kernel(
+        heads_major(q, (-Sq) % bq), heads_major(k, (-Skv) % bk),
+        heads_major(v, (-Skv) % bk), causal=causal, window=window,
+        block_q=bq, block_k=bk, kv_len=Skv, interpret=interpret)
+    return jnp.swapaxes(out[:, :, :Sq], 1, 2)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
@@ -75,14 +78,12 @@ def flash_attention(q, k, v, *, causal=True, window=None,
                     block_q=512, block_k=512, interpret=None):
     """q: (B,Sq,H,hd); k,v: (B,Skv,KV,hd) -> (B,Sq,H,hd).
 
-    ``interpret=None`` auto-selects interpret mode off-TPU (same switch as
-    ``engine.mix_down_count`` uses for psgf_mix). Differentiable via a
-    custom VJP whose backward is the dense oracle's (see module docstring).
+    ``interpret=None`` resolves through :func:`repro.kernels.
+    resolve_interpret`. Differentiable via a custom VJP whose backward is
+    the dense oracle's (see module docstring).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _flash_jit(q, k, v, causal=causal, window=window, block_q=block_q,
-                      block_k=block_k, interpret=interpret)
+                      block_k=block_k, interpret=resolve_interpret(interpret))
 
 
 def _round_up(x: int, m: int) -> int:

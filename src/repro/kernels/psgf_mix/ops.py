@@ -1,5 +1,6 @@
 """Jit'd wrappers for the psgf_mix kernels: 1-D/2-D vector <-> (rows,128)
 layout, padding with mask=0 (padding contributes local values and zero count).
+``interpret=None`` resolves through :func:`repro.kernels.resolve_interpret`.
 """
 from __future__ import annotations
 
@@ -8,6 +9,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.psgf_mix.kernel import (
     LANES, psgf_mix_batch_kernel, psgf_mix_kernel,
 )
@@ -30,10 +32,15 @@ def _pick_block_rows(rows: int, block_rows: int) -> int:
     return 8 * best
 
 
-@partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def psgf_mix(w_global, w_local, mask, *, block_rows=256, interpret=False):
+def psgf_mix(w_global, w_local, mask, *, block_rows=256, interpret=None):
     """w_global/w_local: (D,) float; mask: (D,) bool/float.
     Returns (mixed (D,), count scalar f32)."""
+    return _psgf_mix(w_global, w_local, mask, block_rows=block_rows,
+                     interpret=resolve_interpret(interpret))
+
+
+@partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _psgf_mix(w_global, w_local, mask, *, block_rows, interpret):
     D = w_global.shape[0]
     m = mask.astype(w_global.dtype)
     pad = (-D) % (LANES * 8)
@@ -48,13 +55,18 @@ def psgf_mix(w_global, w_local, mask, *, block_rows=256, interpret=False):
     return mixed.reshape(-1)[:D], jnp.sum(counts)
 
 
-@partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def psgf_mix_batch(w_global, w_clients, mask, *, block_rows=256,
-                   interpret=False):
+                   interpret=None):
     """Client-batched fused mix + comm count (the FL engine's downlink).
 
     w_global: (D,) float; w_clients/mask: (K, D). Returns (mixed (K, D),
     count scalar f32 = sum over ALL clients' realized gates)."""
+    return _psgf_mix_batch(w_global, w_clients, mask, block_rows=block_rows,
+                           interpret=resolve_interpret(interpret))
+
+
+@partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _psgf_mix_batch(w_global, w_clients, mask, *, block_rows, interpret):
     K, D = w_clients.shape
     m = mask.astype(w_clients.dtype)
     pad = (-D) % (LANES * 8)

@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names this TPUCompilerParams; jax>=0.5 renamed it CompilerParams
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, h_scr, *, chunk):
     ic = pl.program_id(2)
@@ -83,7 +80,7 @@ def ssm_scan_kernel(x, dt, Bm, Cm, A, *, chunk=128, d_block=512, interpret=False
         out_specs=pl.BlockSpec((1, chunk, d_block), lambda b, jd, ic: (b, ic, jd)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((d_block, N), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

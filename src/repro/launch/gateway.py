@@ -70,6 +70,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.launch.metrics import DEFAULT_LATENCY_BUCKETS, MetricsRegistry
 
 _REASONS = {
@@ -565,6 +566,7 @@ def main():
     ap.add_argument("--max-batch", type=int, default=32)
     ap.add_argument("--max-wait-ms", type=float, default=2.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     kw = dict(max_batch=args.max_batch, max_wait_ms=args.max_wait_ms)
     if args.manifest:

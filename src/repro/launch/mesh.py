@@ -9,13 +9,12 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the installed jax supports
-    them (jax >= 0.5); older versions (0.4.x, the pinned CI toolchain) only
-    have Auto semantics, so plain make_mesh is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """jax.make_mesh with Auto axis types. A bare make_mesh defaults its axes
+    to Explicit sharding, under which vmap refuses inputs whose mapped axis
+    is sharded differently (the FL engine's client-sharded carry next to
+    replicated per-round keys)."""
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh(shape, axes, axis_types=(auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -108,6 +108,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.forecaster import Forecaster, load_forecaster
 from repro.launch.metrics import DEFAULT_LATENCY_BUCKETS, MetricsRegistry
 
@@ -1210,6 +1211,7 @@ def main():
     ap.add_argument("--queue", action=argparse.BooleanOptionalAction,
                     default=True, help="micro-batching queue vs direct batches")
     args = ap.parse_args()
+    enable_compile_cache()
 
     kw = dict(max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,
               shard_batch=args.shard_batch)

@@ -300,6 +300,61 @@ def test_while_driver_client_sharded_carry():
     assert out["rounds"] == 8
 
 
+_WHILE_SHARDED_PALLAS_CHILD = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import dataclasses, json
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import forecast as F
+from repro.core.fl import engine as E
+from repro.data.synthetic import nn5_synthetic
+from repro.data.windowing import client_datasets
+
+K = {K}
+model_cfg = F.logtst_config(look_back=32, horizon=2, d_model=16, num_heads=2,
+                            d_ff=32, patch_len=8, stride=4)
+fl_cfg = E.FLConfig(policy="psgf", num_clients=K, local_steps=2, batch_size=8)
+series = nn5_synthetic(seed=0, num_clients=K, num_days=200)
+tr, va, te, _ = client_datasets(series, 32, 2)
+tr, te = jnp.asarray(tr), jnp.asarray(te)
+kw = dict(max_rounds=8, patience=9, eval_every=4, driver="while")
+h_ref = E.run_fl(model_cfg, fl_cfg, tr, te, jax.random.PRNGKey(0), **kw)
+h_pl = E.run_fl(model_cfg, dataclasses.replace(fl_cfg, use_pallas_mix=True),
+                tr, te, jax.random.PRNGKey(0), shard_clients=True, **kw)
+print(json.dumps({{
+    "num_devices": len(jax.devices()),
+    "state_devices": len(h_pl["state"]["w_clients"].sharding.device_set),
+    "state_spec": str(h_pl["state"]["w_clients"].sharding.spec),
+    "comm_equal": h_ref["final_comm"] == h_pl["final_comm"],
+    "loss_match": bool(np.allclose(h_ref["train_loss"], h_pl["train_loss"],
+                                   rtol=1e-5)),
+    "rmse_match": bool(np.isclose(h_ref["final_rmse"], h_pl["final_rmse"],
+                                  rtol=1e-5)),
+    "rounds": h_pl["rounds_run"],
+}}))
+"""
+
+
+@pytest.mark.parametrize("K", [8, 6], ids=["rows_sharded", "rows_replicated"])
+def test_while_driver_client_sharded_carry_pallas_mix(K):
+    """The fused psgf_mix downlink on a client-sharded carry over 4 virtual
+    devices: a Pallas kernel cannot be partitioned by the compiler, so under
+    run_fl's client mesh mix_down_count runs it per device through
+    shard_map (rows split when K divides the device count, replicated
+    otherwise). Same comm count, losses and RMSE as the unsharded jnp run."""
+    out = run_child_json(_WHILE_SHARDED_PALLAS_CHILD.format(K=K))
+    assert out["num_devices"] == 4
+    if K % 4 == 0:
+        assert out["state_devices"] == 4 and "clients" in out["state_spec"]
+    else:
+        assert "clients" not in out["state_spec"]
+    assert out["comm_equal"], "fused mix changed the comm count"
+    assert out["loss_match"] and out["rmse_match"], \
+        "sharded fused-mix run diverged from the unsharded jnp run"
+    assert out["rounds"] == 8
+
+
 # ---- fused pallas downlink mix (use_pallas_mix) -----------------------------
 
 
@@ -323,8 +378,8 @@ def test_use_pallas_mix_round_bit_identical():
 
 def test_mix_down_count_fused_matches_unfused():
     """Engine-level fused helper == (mix_down, gate_count) on the element
-    (K, D) path, and the leaf-granularity pytree path is untouched by the
-    flag."""
+    (K, D) path; a leaf-granularity pytree, which the kernel cannot take,
+    raises instead of quietly taking the jnp path."""
     key = jax.random.PRNGKey(0)
     K, D = 5, 700
     ks = jax.random.split(key, 3)
@@ -336,16 +391,22 @@ def test_mix_down_count_fused_matches_unfused():
     mixed, count = E.mix_down_count(clients, glob, gates, use_pallas=True)
     np.testing.assert_array_equal(np.asarray(mixed_ref), np.asarray(mixed))
     assert float(count) == float(count_ref)
-    # pytree (leaf-granularity) input: flag is a no-op, same unfused values
+    # pytree (leaf-granularity) input: the unfused path serves it, and asking
+    # for the kernel there is an error, not a silent fallback
     tree_c = {"a": clients, "b": clients[:, :64]}
     tree_g = {"a": glob, "b": glob[:64]}
     tree_m = {"a": gates, "b": gates[:, :64]}
-    mt, ct = E.mix_down_count(tree_c, tree_g, tree_m, use_pallas=True)
+    mt, ct = E.mix_down_count(tree_c, tree_g, tree_m)
     for k in tree_c:
         np.testing.assert_array_equal(
             np.asarray(E.mix_down(tree_c, tree_g, tree_m)[k]),
             np.asarray(mt[k]))
     assert float(ct) == float(E.gate_count(tree_m, tree_c))
+    with pytest.raises(ValueError, match="use_pallas=True"):
+        E.mix_down_count(tree_c, tree_g, tree_m, use_pallas=True)
+    with pytest.raises(ValueError, match="use_pallas=True"):
+        E.mix_down_count(clients.astype(jnp.bfloat16), glob, gates,
+                         use_pallas=True)
 
 
 # ---- aggregate: all-unselected regression -----------------------------------

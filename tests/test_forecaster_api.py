@@ -165,6 +165,7 @@ def test_run_experiment_matches_hand_assembled_run_fl():
     assert row["rmse"] == hist["final_rmse"]
     assert row["comm_params"] == hist["final_comm"]
     assert row["rounds"] == hist["rounds_run"]
+    assert row["train_loss"] == hist["train_loss"]
     assert row["comm_bytes"] == hist["final_comm"] * 4.0
 
 

@@ -99,18 +99,19 @@ def test_flash_attention_bidirectional_padded_vs_oracle(case, rng_key):
 def test_flash_attention_padded_keys_inert(rng_key):
     """Garbage in the padded KV tail must not reach any output row: the
     kernel masks by kv_len, so poisoning k/v past the true length changes
-    nothing (bidirectional, non-block-multiple lengths)."""
+    nothing (bidirectional, non-block-multiple lengths). The kernel takes
+    heads-major (B, H, S, hd) operands."""
     from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
     ks = jax.random.split(rng_key, 3)
-    q = jax.random.normal(ks[0], (1, 128, 2, 16))
-    k = jax.random.normal(ks[1], (1, 256, 2, 16))
-    v = jax.random.normal(ks[2], (1, 256, 2, 16))
+    q = jax.random.normal(ks[0], (1, 2, 128, 16))
+    k = jax.random.normal(ks[1], (1, 2, 256, 16))
+    v = jax.random.normal(ks[2], (1, 2, 256, 16))
     kv_len = 100                      # rows 100..255 are padding
     base = flash_attention_kernel(q, k, v, causal=False, block_q=128,
                                   block_k=128, kv_len=kv_len, interpret=True)
-    kp = k.at[:, kv_len:].set(50.0)   # large scores if the mask leaked
-    vp = v.at[:, kv_len:].set(-50.0)
+    kp = k.at[:, :, kv_len:].set(50.0)   # large scores if the mask leaked
+    vp = v.at[:, :, kv_len:].set(-50.0)
     poisoned = flash_attention_kernel(q, kp, vp, causal=False, block_q=128,
                                       block_k=128, kv_len=kv_len,
                                       interpret=True)
@@ -125,20 +126,22 @@ def test_flash_attention_fully_masked_rows_zero(rng_key):
     from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
     ks = jax.random.split(rng_key, 3)
-    q = jax.random.normal(ks[0], (1, 256, 2, 16))
-    k = jax.random.normal(ks[1], (1, 128, 2, 16))
-    v = jax.random.normal(ks[2], (1, 128, 2, 16))
+    q = jax.random.normal(ks[0], (1, 2, 256, 16))
+    k = jax.random.normal(ks[1], (1, 2, 128, 16))
+    v = jax.random.normal(ks[2], (1, 2, 128, 16))
     # bidirectional sliding window: q rows with q_pos - window >= kv_len see
     # only padding (valid keys would start past the true kv length)
     out = flash_attention_kernel(q, k, v, causal=False, window=16,
                                  block_q=128, block_k=128, kv_len=100,
                                  interpret=True)
-    dead = np.asarray(out)[0, 120:]   # q_pos >= 116 has no valid key
+    dead = np.asarray(out)[0, :, 120:]   # q_pos >= 116 has no valid key
     np.testing.assert_array_equal(dead, np.zeros_like(dead))
-    live = np.asarray(out)[0, :100]
-    ref = np.asarray(attention_ref(q[:, :100], k[:, :100], v[:, :100],
+    live = np.asarray(out)[0, :, :100]
+    seq_major = lambda x: jnp.swapaxes(x[:, :, :100], 1, 2)
+    ref = np.asarray(attention_ref(seq_major(q), seq_major(k), seq_major(v),
                                    causal=False, window=16))
-    np.testing.assert_allclose(live, ref[0], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(live, np.swapaxes(ref[0], 0, 1),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_flash_attention_grad_matches_oracle(rng_key):
@@ -295,21 +298,7 @@ def test_model_ssm_pallas_path_matches_xla(rng_key):
     cfg = dataclasses.replace(get_config("hymba-1.5b").reduced(), dtype="float32")
     p = spec_init(L.ssm_spec(cfg), rng_key)
     x = 0.1 * jax.random.normal(rng_key, (2, 48, cfg.d_model))
-    # interpret mode flows through ops.ssm_scan's default (interpret=False
-    # fails on CPU), so call the xla path and the kernel path manually:
-    from repro.kernels.ssm_scan.ops import ssm_scan as ssm_kernel_op
+    # the kernel's interpret=None default runs the interpreter on the CPU
     y_x = L.ssm_apply(p, x, cfg, impl="xla")
-    # emulate impl='pallas' with interpret=True
-    s = cfg.ssm
-    xs, z, d_inner, dt_rank = L._ssm_inputs(p, x, cfg)
-    K = s.conv_kernel
-    xs_pad = jnp.pad(xs, ((0, 0), (K - 1, 0), (0, 0)))
-    conv_w = p["conv_w"].astype(x.dtype)
-    xc = sum(xs_pad[:, i: i + xs.shape[1], :] * conv_w[i] for i in range(K))
-    xc = jax.nn.silu(xc + p["conv_b"].astype(x.dtype))
-    dt, Bm, Cm, A = L._ssm_gates(p, xc, cfg, dt_rank)
-    y_k = ssm_kernel_op(xc, dt, Bm, Cm, A, interpret=True)
-    y_k = y_k + xc * p["D"].astype(x.dtype)
-    y_k = y_k * jax.nn.silu(z)
-    y_k = y_k @ p["w_out"].astype(x.dtype)
+    y_k = L.ssm_apply(p, x, cfg, impl="pallas")
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_x), atol=2e-4, rtol=2e-4)
