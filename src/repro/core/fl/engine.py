@@ -75,12 +75,13 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import math
-from functools import partial
+from functools import partial, wraps
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common.pytree_utils import tree_flatten_to_vector, tree_unflatten_from_vector
 from repro.core import forecast
@@ -434,6 +435,21 @@ def init_fl_state(model_cfg: forecast.ForecastConfig, fl_cfg: FLConfig, key,
     return state, meta
 
 
+def _scoped(name: str):
+    """Trace the decorated function under ``jax.named_scope(name)``: the
+    name lands in each of its operations' metadata (the profiler's
+    ``tf_op``), so a device trace can split a compiled round into its
+    stages. A fresh scope per call, since one scope object is not safe to
+    enter from two threads at once."""
+    def deco(f):
+        @wraps(f)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+        return scoped
+    return deco
+
+
 def _local_update(model_cfg, fl_cfg, meta, w, m, v, t, data, key):
     """Per-client LocalUpdate: ``local_steps`` Adam steps on minibatches.
 
@@ -456,20 +472,22 @@ def _local_update(model_cfg, fl_cfg, meta, w, m, v, t, data, key):
 
     def step(carry, skey):
         w, m, v, t = carry
-        idx = jax.random.randint(skey, (fl_cfg.batch_size,), 0, n_win)
-        if streaming:
-            offs = jnp.arange(Lb + model_cfg.horizon)
-            batch = data[idx[:, None] + offs[None, :]]   # (batch, L+T)
-        else:
-            batch = data[idx]
+        with jax.named_scope("fl.window_gather"):
+            idx = jax.random.randint(skey, (fl_cfg.batch_size,), 0, n_win)
+            if streaming:
+                offs = jnp.arange(Lb + model_cfg.horizon)
+                batch = data[idx[:, None] + offs[None, :]]   # (batch, L+T)
+            else:
+                batch = data[idx]
         x, y = batch[:, :Lb], batch[:, Lb:]
         loss, g = jax.value_and_grad(loss_vec)(w, x, y)
-        t = t + 1
-        m = fl_cfg.adam_b1 * m + (1 - fl_cfg.adam_b1) * g
-        v = fl_cfg.adam_b2 * v + (1 - fl_cfg.adam_b2) * jnp.square(g)
-        mhat = m / (1 - fl_cfg.adam_b1 ** t)
-        vhat = v / (1 - fl_cfg.adam_b2 ** t)
-        w = w - fl_cfg.lr * mhat / (jnp.sqrt(vhat) + fl_cfg.adam_eps)
+        with jax.named_scope("fl.adam"):
+            t = t + 1
+            m = fl_cfg.adam_b1 * m + (1 - fl_cfg.adam_b1) * g
+            v = fl_cfg.adam_b2 * v + (1 - fl_cfg.adam_b2) * jnp.square(g)
+            mhat = m / (1 - fl_cfg.adam_b1 ** t)
+            vhat = v / (1 - fl_cfg.adam_b2 ** t)
+            w = w - fl_cfg.lr * mhat / (jnp.sqrt(vhat) + fl_cfg.adam_eps)
         return (w, m, v, t), loss
 
     keys = jax.random.split(key, fl_cfg.local_steps)
@@ -477,6 +495,7 @@ def _local_update(model_cfg, fl_cfg, meta, w, m, v, t, data, key):
     return w, m, v, t, jnp.mean(losses)
 
 
+@_scoped("fl.local_update")
 def _local_update_all(model_cfg, fl_cfg, meta, w, m, v, t, data, keys):
     """LocalUpdate across all K clients: plain vmap, or chunked vmap via
     ``lax.map(batch_size=client_chunk)`` so only ``client_chunk`` clients'
@@ -510,6 +529,7 @@ def sample_cohort(key, num_clients: int, size: int):
     return jax.random.permutation(key, num_clients)[:size]
 
 
+@_scoped("fl.round_down")
 def _round_down(state, key, fl_cfg, meta, policy):
     """Stage 1/3 of a round: client selection, downlink gates, wire payload
     and the downlink mix — everything :func:`_round_body` computes BEFORE
@@ -555,6 +575,7 @@ def _round_down(state, key, fl_cfg, meta, policy):
     return down
 
 
+@_scoped("fl.round_up")
 def _round_up(state, down, upd, fl_cfg, meta, policy):
     """Stage 3/3 of a round: fold the LocalUpdate results back into the
     client rows, uplink gates + wire quantization, aggregation and comm
@@ -812,6 +833,7 @@ _run_while_jit = partial(jax.jit, static_argnames=_WHILE_STATICS,
                          donate_argnames=("state",))(_run_while_impl)
 
 
+@_scoped("fl.eval")
 def _rmse_device(model_cfg: forecast.ForecastConfig, w_vec, meta, data,
                  client_chunk: Optional[int] = None):
     """On-device RMSE of the global model over all clients' test windows.
@@ -1172,27 +1194,30 @@ def run_fl(
                          donate_argnames=("state",),
                          in_shardings=(shardings, None, data_sh, None))
         # statics ride positionally: pjit rejects kwargs with in_shardings
-        with in_mesh():
+        with in_mesh(), TraceAnnotation("fl.dispatch"):
             out = fn(state, key, train_data, test_data, model_cfg, fl_cfg,
                      meta, policy, max_rounds, eval_every, patience)
         state, key, loss_buf, comm_buf, rmse_buf, rounds_dev, chunks_dev = out
-        if multihost:
-            # gather the run-level history to every host ONCE at run end (the
-            # per-round loop stays collective-free beyond the round math)
-            from repro.launch.distributed import fetch
+        with TraceAnnotation("fl.readback"):
+            if multihost:
+                # gather the run-level history to every host ONCE at run end
+                # (the per-round loop stays collective-free beyond the round
+                # math)
+                from repro.launch.distributed import fetch
 
-            loss_buf, comm_buf, rmse_buf, rounds_dev, chunks_dev = (
-                fetch(loss_buf), fetch(comm_buf), fetch(rmse_buf),
-                fetch(rounds_dev), fetch(chunks_dev))
-        rounds_run = int(rounds_dev)      # the ONE host sync of the whole run
-        chunks_run = int(chunks_dev)
-        losses = np.asarray(loss_buf)[:rounds_run]
-        comms = np.asarray(comm_buf)[:rounds_run]
+                loss_buf, comm_buf, rmse_buf, rounds_dev, chunks_dev = (
+                    fetch(loss_buf), fetch(comm_buf), fetch(rmse_buf),
+                    fetch(rounds_dev), fetch(chunks_dev))
+            rounds_run = int(rounds_dev)  # the ONE host sync of the whole run
+            chunks_run = int(chunks_dev)
+            losses = np.asarray(loss_buf)[:rounds_run]
+            comms = np.asarray(comm_buf)[:rounds_run]
+            rmses = np.asarray(rmse_buf)[:chunks_run].tolist()
         history["round"] = list(range(rounds_run))
         history["train_loss"] = losses.tolist()
         history["comm"] = comms.tolist()
         comm_total = float(comms[-1]) if rounds_run else 0.0
-        for i, rmse in enumerate(np.asarray(rmse_buf)[:chunks_run].tolist()):
+        for i, rmse in enumerate(rmses):
             r_end = min((i + 1) * eval_every, max_rounds) - 1
             history["rmse"].append((r_end, rmse))
             if verbose:
@@ -1209,8 +1234,9 @@ def run_fl(
     else:
         final_rmse = evaluate_rmse(model_cfg, state["w_global"], meta,
                                    test_data, fl_cfg.client_chunk)
-    return _finalize_history(history, state, meta, model_cfg, fl_cfg,
-                             final_rmse, comm_total, checkpoint_dir)
+    with TraceAnnotation("fl.finalize"):
+        return _finalize_history(history, state, meta, model_cfg, fl_cfg,
+                                 final_rmse, comm_total, checkpoint_dir)
 
 
 def _finalize_history(history, state, meta, model_cfg, fl_cfg, final_rmse,

@@ -31,14 +31,23 @@ module turns those checkpoints into a batched, routed inference endpoint:
     a held-out day of ``ForecastTask`` windows through the queue in arrival
     order and tracks per-cluster ONLINE RMSE (a per-request timeout skips and
     counts stuck futures instead of stalling the whole replay);
-  * every server carries a ``repro.launch.metrics.MetricsRegistry``
-    (``metrics=False`` opts out): the worker loop records submit->result
-    latency histograms, per-(cluster, shape) batch fill and padded-slot
-    waste, per-cluster request/series counters and reject/error tallies —
+  * every server counts through one ``repro.launch.metrics.MetricsRegistry``
+    (``ForecastServer.stats`` and ``cluster_stats`` are read-only views of
+    it; ``metrics=False`` only hides it from exposition): the worker loop
+    records submit->result latency histograms, per-(cluster, shape) batch
+    fill and padded-slot waste, per-cluster request/series counters,
+    reject/error tallies and the process's garbage-collector pauses —
     dumped by :meth:`ForecastServer.metrics_text` and served over HTTP at
     ``GET /metricz`` by ``repro.launch.gateway.ForecastGateway``, the
     production front door (auth, rate limiting, load shedding) for this
     server;
+  * the serving path names its steps for the profiler
+    (``jax.profiler.TraceAnnotation``, about a microsecond each when no
+    trace is running): ``serve.submit`` on the caller's thread; on the
+    worker ``serve.queue_wait``, ``serve.coalesce`` and, per dispatched
+    group, ``serve.group`` around ``serve.assemble``, ``serve.step``,
+    ``serve.copy_back`` and ``serve.resolve``; ``gc.collect`` around each
+    collection while a server is started (docs/serving.md);
   * :meth:`ForecastServer.close` is the TERMINAL shutdown: it stops the
     worker, fails every still-pending future with ``RuntimeError``, and
     fails anything submitted afterwards — waiters never hang on a dead
@@ -95,6 +104,7 @@ checkpoint -> routed serving -> streaming eval) in
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import os
 import queue
@@ -102,11 +112,13 @@ import threading
 import time
 from concurrent.futures import Future, InvalidStateError
 from functools import lru_cache
+from types import MappingProxyType
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common.compile_cache import enable_compile_cache
 from repro.core.forecaster import Forecaster, load_forecaster
@@ -129,6 +141,58 @@ def _safe_set(fut: Future, result=None, exc: Optional[BaseException] = None):
             fut.set_result(result)
     except InvalidStateError:
         pass
+
+
+# --- collector pauses --------------------------------------------------------
+# ONE gc.callbacks hook for the whole process, shared by every started server:
+# a collection holds the interpreter lock, so it stalls the worker and every
+# caller at once, whichever server's heap it walks.
+_GC_SINKS: tuple = ()       # started servers' per-generation gc counters
+_GC_LOCK = threading.Lock()  # guards (un)registration; never taken by the hook
+_gc_open = None             # (span, start) of the collection in progress
+
+
+def _gc_hook(phase: str, info: dict):
+    """A ``gc.collect`` span around each collection, and its count and pause
+    in every started server's registry. The collector runs one collection at
+    a time, so a ``start`` and its ``stop`` pair up."""
+    global _gc_open
+    if phase == "start":
+        span = TraceAnnotation("gc.collect", generation=info["generation"])
+        span.__enter__()
+        _gc_open = (span, time.perf_counter())
+        return
+    if _gc_open is None:
+        return
+    span, t0 = _gc_open
+    _gc_open = None
+    pause = time.perf_counter() - t0
+    span.__exit__(None, None, None)
+    g = info["generation"]
+    for counts, pauses in _GC_SINKS:
+        counts[g].inc()
+        pauses[g].inc(pause)
+
+
+def _gc_watch(sink, on: bool):
+    """Add (``on``) or remove one server's gc counters; the hook is in
+    ``gc.callbacks`` exactly while some server is watching."""
+    global _GC_SINKS
+    with _GC_LOCK:
+        sinks = tuple(s for s in _GC_SINKS if s is not sink)
+        _GC_SINKS = sinks + (sink,) if on else sinks
+        hooked = _gc_hook in gc.callbacks
+        if _GC_SINKS and not hooked:
+            gc.callbacks.append(_gc_hook)
+        elif not _GC_SINKS and hooked:
+            gc.callbacks.remove(_gc_hook)
+
+
+def _total(family, **match) -> int:
+    """Sum of a counter family's series whose labels include ``match``."""
+    return int(sum(child.get() for values, child in family.samples()
+                   if all(dict(zip(family.label_names, values))[k] == v
+                          for k, v in match.items())))
 
 
 def batch_buckets(max_batch: int) -> Tuple[int, ...]:
@@ -182,18 +246,20 @@ class _ClusterEngine:
         it again."""
         bucket, M, _ = x.shape
         T = self.forecaster.cfg.horizon
-        xj = jnp.asarray(x, jnp.float32)
-        shard = self.shardings is not None and bucket % self._ndev == 0
-        if shard:
-            xj = jax.device_put(xj, self.shardings[0])
-        key = (bucket, M)
-        out = self._out.pop(key, None)
-        if out is None:
-            out = jnp.zeros((bucket, M, T), jnp.float32)
+        with TraceAnnotation("serve.step"):
+            xj = jnp.asarray(x, jnp.float32)
+            shard = self.shardings is not None and bucket % self._ndev == 0
             if shard:
-                out = jax.device_put(out, self.shardings[0])
-        out = self._step(self.params, xj, out)
-        result = np.asarray(out[:rows])
+                xj = jax.device_put(xj, self.shardings[0])
+            key = (bucket, M)
+            out = self._out.pop(key, None)
+            if out is None:
+                out = jnp.zeros((bucket, M, T), jnp.float32)
+                if shard:
+                    out = jax.device_put(out, self.shardings[0])
+            out = self._step(self.params, xj, out)
+        with TraceAnnotation("serve.copy_back"):
+            result = np.asarray(out[:rows])
         self._out[key] = out
         return result
 
@@ -305,17 +371,18 @@ class ForecastServer:
         self._staged_gen: Optional[_Generation] = None
         self._watch_thread: Optional[threading.Thread] = None
         self._watch_stop: Optional[threading.Event] = None
-        self.stats = {"requests": 0, "batches": 0, "padded_slots": 0,
-                      "series_served": 0, "reloads": 0}
-        self.cluster_stats = {c: {"requests": 0, "series_served": 0}
-                              for c in self._gen.engines}
+        # cluster labels -> the cluster keys of every generation published,
+        # so the per-cluster tallies map back to the keys callers route by
+        self._cluster_keys = {str(c): c for c in self._gen.engines}
         self._queue: "queue.Queue" = queue.Queue()
         self._worker_thread: Optional[threading.Thread] = None
         self._closed = False
         self._lifecycle = threading.Lock()  # guards _closed vs enqueue
-        self.metrics: Optional[MetricsRegistry] = None
-        if metrics:
-            self._init_metrics()
+        self._init_metrics()
+        # metrics=False hides the registry (no exposition); the server still
+        # counts through it, since stats and cluster_stats read it
+        self.metrics: Optional[MetricsRegistry] = (
+            self._registry if metrics else None)
 
     # --- generation snapshot (compat views) -------------------------------
     @property
@@ -339,20 +406,33 @@ class ForecastServer:
     def _default(self):
         return self._gen.default
 
-    def _cluster_stats(self, cluster) -> dict:
-        """Per-cluster tallies survive swaps; a reload that introduces a new
-        cluster label grows the table on first traffic."""
-        st = self.cluster_stats.get(cluster)
-        if st is None:
-            st = self.cluster_stats.setdefault(
-                cluster, {"requests": 0, "series_served": 0})
-        return st
+    @property
+    def stats(self):
+        """Server-wide tallies, read from the registry's counters (a
+        read-only snapshot): requests accepted, batches dispatched, bucket
+        slots padded, series served, hot swaps made."""
+        return MappingProxyType({
+            "requests": _total(self._m_requests),
+            "batches": _total(self._m_batches),
+            "padded_slots": _total(self._m_padded),
+            "series_served": _total(self._m_series),
+            "reloads": _total(self._m_reloads, outcome="swapped")})
+
+    @property
+    def cluster_stats(self):
+        """Per-cluster requests and series served, read from the registry's
+        counters (a read-only snapshot). Tallies survive swaps; every
+        cluster of every generation published has an entry."""
+        return MappingProxyType({
+            c: {"requests": _total(self._m_requests, cluster=lbl),
+                "series_served": _total(self._m_series, cluster=lbl)}
+            for lbl, c in list(self._cluster_keys.items())})
 
     def _init_metrics(self):
         """Declare the serving metric families (catalogued in
         docs/serving.md). Hot-path recordings go through the cached label
         children, so steady-state cost is a dict hit + a locked float add."""
-        m = self.metrics = MetricsRegistry()
+        m = self._registry = MetricsRegistry()
         self._m_requests = m.counter(
             "forecast_requests_total",
             "submit() requests accepted into the micro-batch queue",
@@ -405,6 +485,19 @@ class ForecastServer:
             "forecast_reloads_total",
             "manifest hot-swaps by outcome (swapped/stale/waiting/error)",
             ("outcome",))
+        gc_runs = m.counter(
+            "forecast_gc_collections_total",
+            "garbage collections in the serving process while it is started",
+            ("generation",))
+        gc_pause = m.counter(
+            "forecast_gc_pause_seconds_total",
+            "seconds those collections held the interpreter lock",
+            ("generation",))
+        # children made up front: the hook then never takes a family lock
+        # (a collection can start while this thread holds one)
+        gens = range(len(gc.get_count()))
+        self._gc_sink = ({g: gc_runs.labels(g) for g in gens},
+                         {g: gc_pause.labels(g) for g in gens})
 
     def metrics_text(self) -> str:
         """Prometheus text exposition of the server registry (the body the
@@ -563,8 +656,7 @@ class ForecastServer:
         with self._reload_lock:
             generation, manifest = read_routing_manifest(src["root"])
             if generation <= self._gen.generation:
-                if self.metrics is not None:
-                    self._m_reloads.labels("stale").inc()
+                self._m_reloads.labels("stale").inc()
                 return False
             staged = self._staged_gen
             if staged is not None and staged.generation == generation:
@@ -590,6 +682,8 @@ class ForecastServer:
                         generation, engines,
                         station_cluster=manifest["station_cluster"],
                         station_norm=station_norm, sources=sources)
+                    for c in engines:
+                        self._cluster_keys.setdefault(str(c), c)
                     fresh = [c for c, e in engines.items()
                              if e is not old.engines.get(c)]
                     for ch in warm_channels:
@@ -600,21 +694,17 @@ class ForecastServer:
                                     np.zeros((b, ch, L), np.float32), c,
                                     new_gen)
                 except Exception:
-                    if self.metrics is not None:
-                        self._m_reloads.labels("error").inc()
+                    self._m_reloads.labels("error").inc()
                     raise
             if self.process_shard is not None and self.process_shard[1] > 1:
                 if not self._announce_and_await(src["root"], generation,
                                                 sync_timeout_s):
                     self._staged_gen = new_gen   # reuse next tick, no rebuild
-                    if self.metrics is not None:
-                        self._m_reloads.labels("waiting").inc()
+                    self._m_reloads.labels("waiting").inc()
                     return False
             self._gen = new_gen   # THE swap: one atomic attribute store
             self._staged_gen = None
-            self.stats["reloads"] += 1
-            if self.metrics is not None:
-                self._m_reloads.labels("swapped").inc()
+            self._m_reloads.labels("swapped").inc()
         return True
 
     def _announce_and_await(self, root: str, generation: int,
@@ -773,16 +863,11 @@ class ForecastServer:
             x = np.concatenate(
                 [x, np.zeros((bucket - b, M, L), np.float32)], axis=0)
         result = gen.engines[cluster].run_padded(x, b)
-        self.stats["batches"] += 1
-        self.stats["padded_slots"] += bucket - b
-        self.stats["series_served"] += b * M
-        self._cluster_stats(cluster)["series_served"] += b * M
-        if self.metrics is not None:
-            lbl = (str(cluster), f"{M}x{L}")
-            self._m_batches.labels(*lbl).inc()
-            self._m_padded.labels(*lbl).inc(bucket - b)
-            self._m_fill.labels(*lbl).observe(b / bucket)
-            self._m_series.labels(str(cluster)).inc(b * M)
+        lbl = (str(cluster), f"{M}x{L}")
+        self._m_batches.labels(*lbl).inc()
+        self._m_padded.labels(*lbl).inc(bucket - b)
+        self._m_fill.labels(*lbl).observe(b / bucket)
+        self._m_series.labels(str(cluster)).inc(b * M)
         return result
 
     def predict(self, x, station=None, cluster=None) -> np.ndarray:
@@ -835,6 +920,7 @@ class ForecastServer:
             raise RuntimeError("ForecastServer is closed")
         if self._worker_thread is not None:
             return
+        _gc_watch(self._gc_sink, True)
         self._worker_thread = threading.Thread(target=self._worker, daemon=True)
         self._worker_thread.start()
 
@@ -852,6 +938,10 @@ class ForecastServer:
         own future — it never reaches the queue, so the micro-batch it would
         have been coalesced into is unaffected.
         """
+        with TraceAnnotation("serve.submit"):
+            return self._submit(x, station, cluster)
+
+    def _submit(self, x, station, cluster) -> Future:
         fut: Future = Future()
         gen = self._gen  # ONE snapshot read: route, norm and serve cohere
         try:
@@ -867,10 +957,8 @@ class ForecastServer:
             if norm is not None:
                 x = (x - norm[0]) / norm[1]
         except Exception as exc:  # incl. ragged/non-numeric asarray failures
-            if self.metrics is not None:
-                kind = ("unroutable" if isinstance(exc, KeyError)
-                        else "malformed")
-                self._m_rejected.labels(kind).inc()
+            kind = "unroutable" if isinstance(exc, KeyError) else "malformed"
+            self._m_rejected.labels(kind).inc()
             fut.set_exception(exc)
             return fut
         with self._lifecycle:
@@ -882,10 +970,8 @@ class ForecastServer:
                 fut.set_exception(RuntimeError(
                     "ForecastServer is closed; request was not enqueued"))
                 return fut
-            self.stats["requests"] += 1
-            self._cluster_stats(cluster)["requests"] += 1
-            if self.metrics is not None:
-                self._m_requests.labels(str(cluster)).inc()
+            self._m_requests.labels(str(cluster)).inc()
+            if self.metrics is not None:  # stats does not read latency
                 lat = self._m_latency.labels(str(cluster))
                 t0 = time.perf_counter()
                 fut.add_done_callback(
@@ -922,6 +1008,7 @@ class ForecastServer:
         self._queue.put(_STOP)
         self._worker_thread.join()
         self._worker_thread = None
+        _gc_watch(self._gc_sink, False)
 
     def close(self):
         """TERMINAL shutdown: stop the worker and fail EVERY still-pending
@@ -955,20 +1042,26 @@ class ForecastServer:
         so a waiter that cancelled (gateway deadline) can't blow up the
         worker thread."""
         gen, cluster = items[0][0], items[0][1]
-        try:
-            ys = self._predict(gen, np.stack([x for _, _, x, _ in items]),
-                               cluster=cluster)
-            for (_, _, _, fut), y in zip(items, ys):
-                _safe_set(fut, y)
-        except Exception as exc:
-            if self.metrics is not None:
+        with TraceAnnotation("serve.group", cluster=str(cluster),
+                             bucket=self.bucket_for(len(items)),
+                             rows=len(items)):
+            try:
+                with TraceAnnotation("serve.assemble"):
+                    x = np.stack([x for _, _, x, _ in items])
+                ys = self._predict(gen, x, cluster=cluster)
+            except Exception as exc:
                 self._m_errors.labels(str(cluster)).inc()
-            for _, _, _, fut in items:
-                _safe_set(fut, exc=exc)
+                for _, _, _, fut in items:
+                    _safe_set(fut, exc=exc)
+                return
+            with TraceAnnotation("serve.resolve"):
+                for (_, _, _, fut), y in zip(items, ys):
+                    _safe_set(fut, y)
 
     def _worker(self):
         while True:
-            item = self._queue.get()
+            with TraceAnnotation("serve.queue_wait"):
+                item = self._queue.get()
             if item is _STOP:
                 return
             # coalesced requests are heterogeneous in routed cluster AND in
@@ -995,22 +1088,23 @@ class ForecastServer:
             cap = self.max_batch * max(1, len(self.engines))
             deadline = time.perf_counter() + self.max_wait_ms / 1e3
             stopping = False
-            while total < cap:
-                for k in [k for k, v in groups.items()
-                          if len(v) >= self.max_batch]:
-                    self._run_group(groups.pop(k))
-                left = deadline - time.perf_counter()
-                if left <= 0:
-                    break
-                try:
-                    nxt = self._queue.get(timeout=left)
-                except queue.Empty:
-                    break
-                if nxt is _STOP:
-                    stopping = True
-                    break
-                groups.setdefault(key_of(nxt), []).append(nxt)
-                total += 1
+            with TraceAnnotation("serve.coalesce"):
+                while total < cap:
+                    for k in [k for k, v in groups.items()
+                              if len(v) >= self.max_batch]:
+                        self._run_group(groups.pop(k))
+                    left = deadline - time.perf_counter()
+                    if left <= 0:
+                        break
+                    try:
+                        nxt = self._queue.get(timeout=left)
+                    except queue.Empty:
+                        break
+                    if nxt is _STOP:
+                        stopping = True
+                        break
+                    groups.setdefault(key_of(nxt), []).append(nxt)
+                    total += 1
             for items in groups.values():
                 self._run_group(items)
             if stopping:
