@@ -72,15 +72,15 @@ _stage_up = partial(
 @partial(jax.jit, static_argnames=("model_cfg", "meta"))
 def _chunk_sse(w_vec, data, model_cfg, meta):
     """Sum of squared forecast errors of the global model over one client
-    chunk's raw ``(C, T)`` test slice (stride-1 windows gathered on device —
-    the chunk slice is the only test-data device residency)."""
+    chunk's raw ``(C, T)`` test slice (stride-1 windows built on device from
+    static slices of each client's row — the chunk slice is the only
+    test-data device residency)."""
     params = E.tree_unflatten_from_vector(w_vec, meta)
     Lb, H = model_cfg.look_back, model_cfg.horizon
     W = Lb + H
     C = data.shape[0]
     n = data.shape[1] - W + 1
-    widx = jnp.arange(n)[:, None] + jnp.arange(W)[None, :]
-    win = data[:, widx]                                   # (C, n, W)
+    win = E._all_windows(data, W)                         # (C, n, W)
     pred = forecast.forward(model_cfg, params,
                             win[:, :, :Lb].reshape(C * n, Lb))
     return jnp.sum(jnp.square(pred - win[:, :, Lb:].reshape(C * n, H)))

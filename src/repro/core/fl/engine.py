@@ -450,16 +450,40 @@ def _scoped(name: str):
     return deco
 
 
+def _all_windows(series, width: int):
+    """Every stride-1 ``width``-long window of ``series`` along its last axis:
+    ``(..., n, width)`` with ``n = series.shape[-1] - width + 1``, window
+    ``i`` == ``series[..., i : i + width]``. Built from ``width`` static
+    slices (column ``j`` is ``series[..., j : j + n]``), so it is pure data
+    movement, with no gather at all."""
+    n = series.shape[-1] - width + 1
+    return jnp.stack([series[..., j:j + n] for j in range(width)], axis=-1)
+
+
+def _windows(row, starts, width: int):
+    """The ``width``-long windows of the 1-D ``row`` that begin at ``starts``:
+    ``(len(starts), width)``, row ``k`` == ``row[starts[k] : starts[k] +
+    width]``. One row gather over :func:`_all_windows` fetches each window as
+    one contiguous slice. Indexing the row itself instead, scalar by scalar
+    (``row[starts[:, None] + arange(width)]``) or window by window
+    (``lax.dynamic_slice`` under ``vmap``), compiles for the TPU to a gather
+    of single elements or to a loop over the windows: on a TPU v5e, at the
+    paper's 58 clients and batch 32 over four steps, ~10 ms against ~0.7 ms
+    for building every window and taking rows."""
+    return _all_windows(row, width)[starts]
+
+
 def _local_update(model_cfg, fl_cfg, meta, w, m, v, t, data, key):
     """Per-client LocalUpdate: ``local_steps`` Adam steps on minibatches.
 
     data: ONE client's ``(n_win, L+T)`` materialized windows, or its raw
     ``(T,)`` series slice under ``streaming_windows`` — the minibatch draw is
-    then a START-INDEX draw and the ``(batch, L+T)`` windows are gathered from
-    the raw row in one ``jnp`` gather. Window ``i`` of the raw slice is
-    ``data[i : i + L+T]`` == materialized row ``i``, and the index draw uses
-    the same bounds, so both layouts see bit-identical minibatches under the
-    same RNG. Operates on the flat vector.
+    then a START-INDEX draw and the ``(batch, L+T)`` windows are taken as
+    whole rows of the raw row's window view (:func:`_windows`).
+    Window ``i`` of the raw slice is ``data[i : i + L+T]`` == materialized
+    row ``i``, and the index draw uses the same bounds, so both layouts see
+    bit-identical minibatches under the same RNG. Operates on the flat
+    vector.
     """
     Lb = model_cfg.look_back
     streaming = data.ndim == 1
@@ -475,8 +499,7 @@ def _local_update(model_cfg, fl_cfg, meta, w, m, v, t, data, key):
         with jax.named_scope("fl.window_gather"):
             idx = jax.random.randint(skey, (fl_cfg.batch_size,), 0, n_win)
             if streaming:
-                offs = jnp.arange(Lb + model_cfg.horizon)
-                batch = data[idx[:, None] + offs[None, :]]   # (batch, L+T)
+                batch = _windows(data, idx, Lb + model_cfg.horizon)
             else:
                 batch = data[idx]
         x, y = batch[:, :Lb], batch[:, Lb:]
@@ -840,7 +863,8 @@ def _rmse_device(model_cfg: forecast.ForecastConfig, w_vec, meta, data,
 
     data: (K, n_win, L+T) materialized windows, or the raw (K, T) test-split
     series slice under ``streaming_windows`` — the stride-1 windows are then
-    gathered on device (per client inside the chunked ``lax.map``, so only
+    built on device from static slices of each client's row
+    (:func:`_all_windows`; per client inside the chunked ``lax.map``, so only
     ``client_chunk`` clients' windows exist at once; the raw slice is the only
     resident copy of the test data). With ``client_chunk`` the forward runs
     per client through ``lax.map(batch_size=client_chunk)`` so at most
@@ -859,17 +883,16 @@ def _rmse_device(model_cfg: forecast.ForecastConfig, w_vec, meta, data,
     streaming = data.ndim == 2
     K = data.shape[0]
     n = data.shape[1] - W + 1 if streaming else data.shape[1]
-    widx = jnp.arange(n)[:, None] + jnp.arange(W)[None, :] if streaming else None
     if client_chunk is not None and client_chunk < K:
-        win = (lambda cl: cl[widx]) if streaming else (lambda cl: cl)
+        win = (lambda cl: _all_windows(cl, W)) if streaming else (lambda cl: cl)
         pred = jax.lax.map(
             lambda cl: forecast.forward(model_cfg, params, win(cl)[:, :Lb]),
             data, batch_size=client_chunk)
         pred = pred.reshape(K * n, H)
-        # (K, n, H) truth gather is O(K*n*H) — horizon-sized, never windowed
-        y = data[:, widx[:, Lb:]] if streaming else data[:, :, Lb:]
+        # (K, n, H) truth: horizon-wide windows only, never the full L+T
+        y = _all_windows(data[:, Lb:], H) if streaming else data[:, :, Lb:]
     else:
-        win = data[:, widx] if streaming else data       # (K, n, W)
+        win = _all_windows(data, W) if streaming else data     # (K, n, W)
         x = win[:, :, :Lb].reshape(K * n, Lb)
         pred = forecast.forward(model_cfg, params, x)
         y = win[:, :, Lb:]
@@ -882,7 +905,7 @@ def evaluate_rmse(model_cfg: forecast.ForecastConfig, w_vec, meta, data,
     """RMSE of the global model over all clients' test windows.
 
     data: (K, n_win, L+T) materialized windows or the raw (K, T) test-split
-    slice (streaming — windows gathered on device). ``client_chunk`` chunks
+    slice (streaming — windows built on device). ``client_chunk`` chunks
     the forward over clients (see :func:`_rmse_device`); ``None`` keeps the
     single flat forward.
     """

@@ -11,9 +11,11 @@ Two layouts feed the FL engine:
     ~``(L+T)``x, so host->device transfer and device residency become the
     ceiling on client count long before compute does.
   * STREAMING (:func:`client_series` / :func:`client_series_datasets`) — the
-    raw normalized ``(K, T)`` series plus split boundaries; the engine gathers
+    raw normalized ``(K, T)`` series plus split boundaries; the engine fetches
     ``(batch, L+T)`` windows ON DEVICE inside the compiled round loop
-    (``FLConfig.streaming_windows``). Window ``i`` of a raw slice is
+    (``FLConfig.streaming_windows``): it builds every window of a client's
+    row from ``L + T`` static slices and takes the drawn windows as whole
+    rows, never element by element. Window ``i`` of a raw slice is
     ``slice[i : i + L + T]`` — bit-identical values to the materialized
     tensor's row ``i``, at ~``(L+T)``x less memory.
 """
@@ -146,7 +148,7 @@ def client_series_datasets(series: np.ndarray, look_back: int, horizon: int,
     """Streaming counterpart of :func:`client_datasets`: same cleaning and
     normalization, but returns the three RAW ``(K, T_*)`` split slices
     (:func:`split_series`) instead of materialized window tensors. The FL
-    engine (``FLConfig.streaming_windows``) gathers windows from these on
+    engine (``FLConfig.streaming_windows``) builds windows from these on
     device — bit-identical values at ~``(L+T)``x less memory."""
     series, split_idx, info = client_series(series, look_back, horizon,
                                             normalize)
