@@ -856,6 +856,29 @@ _run_while_jit = partial(jax.jit, static_argnames=_WHILE_STATICS,
                          donate_argnames=("state",))(_run_while_impl)
 
 
+# The single flat eval forward holds every test window's attention scores at
+# once, (K * n_win, heads, tokens, tokens) float32 per attention layer. Past
+# this many bytes it runs over blocks of clients instead, as ``client_chunk``
+# makes it (same reduction, same result). On a TPU v5e, PatchTST/64's flat
+# eval (1.5 GB of scores a layer) compiled into the ``while`` job changed the
+# float32 arithmetic of the job's training rounds (round 0's loss 2.8e-5 off
+# the reference, 1.7e-2 by round 2); in blocks the rounds match the
+# reference and the scan driver.
+EVAL_SCORE_BYTES = 256 * 2 ** 20
+
+
+def _eval_chunk(model_cfg: forecast.ForecastConfig, num_clients: int,
+                n_win: int, client_chunk: Optional[int]) -> Optional[int]:
+    """``client_chunk`` if given, else the most clients whose eval scores fit
+    :data:`EVAL_SCORE_BYTES` (``None``, the flat forward, when all do)."""
+    if client_chunk is not None or "attn" not in model_cfg.mixers:
+        return client_chunk
+    per_client = n_win * model_cfg.num_heads * model_cfg.num_tokens ** 2 * 4
+    if num_clients * per_client <= EVAL_SCORE_BYTES:
+        return None
+    return max(1, EVAL_SCORE_BYTES // per_client)
+
+
 @_scoped("fl.eval")
 def _rmse_device(model_cfg: forecast.ForecastConfig, w_vec, meta, data,
                  client_chunk: Optional[int] = None):
@@ -870,9 +893,12 @@ def _rmse_device(model_cfg: forecast.ForecastConfig, w_vec, meta, data,
     per client through ``lax.map(batch_size=client_chunk)`` so at most
     ``client_chunk * n_win`` windows' activations are live at once (the single
     flat forward materializes all ``K * n_win`` — OOM at num_clients=512
-    full-preset). The reduction always runs over the full (K*n, T) prediction
-    matrix in the same order, so the chunked result matches the flat one and
-    both layouts match each other (bitwise on the pinned CPU toolchain).
+    full-preset). Without ``client_chunk``, an attention model whose flat
+    forward would hold more than :data:`EVAL_SCORE_BYTES` of scores runs in
+    client blocks too (:func:`_eval_chunk`). The reduction always runs over
+    the full (K*n, T) prediction matrix in the same order, so the chunked
+    result matches the flat one and both layouts match each other (bitwise
+    on the pinned CPU toolchain).
     Returns a scalar jnp array (jit-safe; the while driver calls this inside
     its one-dispatch loop).
     """
@@ -883,6 +909,7 @@ def _rmse_device(model_cfg: forecast.ForecastConfig, w_vec, meta, data,
     streaming = data.ndim == 2
     K = data.shape[0]
     n = data.shape[1] - W + 1 if streaming else data.shape[1]
+    client_chunk = _eval_chunk(model_cfg, K, n, client_chunk)
     if client_chunk is not None and client_chunk < K:
         win = (lambda cl: _all_windows(cl, W)) if streaming else (lambda cl: cl)
         pred = jax.lax.map(

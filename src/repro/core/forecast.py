@@ -152,7 +152,8 @@ def detokenize_spec(cfg: ForecastConfig):
 def detokenize(params, tok):
     """Pred = MLP{Concat[Flat(V_0), Flat(V_1), ...]} (eq. 1)."""
     B = tok.shape[0]
-    return tok.reshape(B, -1) @ params["w"] + params["b"]
+    with jax.named_scope("forecast.head"):
+        return tok.reshape(B, -1) @ params["w"] + params["b"]
 
 
 # ---------------------------------------------------------------------------
@@ -226,20 +227,21 @@ def _self_attn(p, x, cfg: ForecastConfig):
     (B, H, N, N) score matrix); the default keeps the dense einsum path
     bitwise unchanged. Both share the projections and output mix.
     """
-    hd = cfg.d_model // cfg.num_heads
-    q = jnp.einsum("bnd,dhk->bnhk", x, p["wq"]) + p["bq"]
-    k = jnp.einsum("bnd,dhk->bnhk", x, p["wk"]) + p["bk"]
-    v = jnp.einsum("bnd,dhk->bnhk", x, p["wv"]) + p["bv"]
-    if cfg.use_flash_attn:
-        from repro.kernels.flash_attention.ops import flash_attention
+    with jax.named_scope("forecast.attn"):
+        hd = cfg.d_model // cfg.num_heads
+        q = jnp.einsum("bnd,dhk->bnhk", x, p["wq"]) + p["bq"]
+        k = jnp.einsum("bnd,dhk->bnhk", x, p["wk"]) + p["bk"]
+        v = jnp.einsum("bnd,dhk->bnhk", x, p["wv"]) + p["bv"]
+        if cfg.use_flash_attn:
+            from repro.kernels.flash_attention.ops import flash_attention
 
-        # tokens attend bidirectionally (eq. 2), so causal=False
-        o = flash_attention(q, k, v, causal=False)
-    else:
-        s = jnp.einsum("bnhk,bmhk->bhnm", q, k) / math.sqrt(hd)
-        a = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-        o = jnp.einsum("bhnm,bmhk->bnhk", a, v)
-    return jnp.einsum("bnhk,hkd->bnd", o, p["wo"]) + p["bo"]
+            # tokens attend bidirectionally (eq. 2), so causal=False
+            o = flash_attention(q, k, v, causal=False)
+        else:
+            s = jnp.einsum("bnhk,bmhk->bhnm", q, k) / math.sqrt(hd)
+            a = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
+            o = jnp.einsum("bhnm,bmhk->bnhk", a, v)
+        return jnp.einsum("bnhk,hkd->bnd", o, p["wo"]) + p["bo"]
 
 
 def block_apply(params, x, cfg: ForecastConfig, mixer: str):
@@ -256,7 +258,8 @@ def block_apply(params, x, cfg: ForecastConfig, mixer: str):
         x = x + h  # identity mixer: the sublayer reduces to the norm residual
     h = _ln(params["ln2"], x)
     m = params["mlp"]
-    x = x + (jax.nn.gelu(h @ m["w1"] + m["b1"]) @ m["w2"] + m["b2"])
+    with jax.named_scope("forecast.mlp"):
+        x = x + (jax.nn.gelu(h @ m["w1"] + m["b1"]) @ m["w2"] + m["b2"])
     return x
 
 

@@ -1,8 +1,9 @@
 """The program's own names for the profiler, and the server's counters:
 
   * a compiled ``run_fl(driver="while")`` job carries every ``fl.*`` stage
-    scope in its operations' metadata (what a device trace's ``tf_op``
-    shows);
+    scope and the forecaster's ``forecast.*`` scopes in its operations'
+    metadata (what a device trace's ``tf_op`` shows), and the scopes leave
+    the job's results bitwise as they are without them;
   * served requests under ``jax.profiler`` yield every ``serve.*`` span, the
     per-group steps nested in ``serve.group``, one ``serve.group`` per
     dispatched batch, and a ``gc.collect`` span per collection;
@@ -33,6 +34,7 @@ from repro.launch.serve_forecast import ForecastServer
 
 STAGES = ("fl.round_down", "fl.local_update", "fl.window_gather", "fl.adam",
           "fl.round_up", "fl.eval")
+MODEL_SCOPES = ("forecast.attn", "forecast.mlp", "forecast.head")
 STEPS = ("serve.assemble", "serve.step", "serve.copy_back", "serve.resolve")
 SERVE_SPANS = ("serve.submit", "serve.queue_wait", "serve.coalesce",
                "serve.group") + STEPS
@@ -43,18 +45,24 @@ TINY = dict(look_back=16, horizon=2, d_model=16, num_heads=2, d_ff=16,
 # ---- device scopes ----------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def while_job_op_names():
-    """Every ``op_name`` in the compiled HLO of a tiny ``while`` job."""
+def _tiny_job():
+    """A tiny LoGTST ``while`` job's model, FL configuration and data."""
     model_cfg = F.logtst_config(look_back=32, horizon=2, d_model=16,
                                 num_heads=2, d_ff=32, patch_len=8, stride=4)
     fl_cfg = E.FLConfig(policy="psgf", num_clients=4, local_steps=2,
                         batch_size=8, streaming_windows=True)
     tr, _, te, _ = client_series_datasets(
         nn5_synthetic(seed=0, num_clients=4, num_days=200), 32, 2)
+    return model_cfg, fl_cfg, jnp.asarray(tr), jnp.asarray(te)
+
+
+@pytest.fixture(scope="module")
+def while_job_op_names():
+    """Every ``op_name`` in the compiled HLO of a tiny ``while`` job."""
+    model_cfg, fl_cfg, tr, te = _tiny_job()
     state, meta = E.init_fl_state(model_cfg, fl_cfg, jax.random.PRNGKey(0))
     hlo = E._run_while_jit.lower(
-        state, jax.random.PRNGKey(1), jnp.asarray(tr), jnp.asarray(te),
+        state, jax.random.PRNGKey(1), tr, te,
         model_cfg, fl_cfg, meta, pol.from_config(fl_cfg), 4, 2, 5,
     ).compile().as_text()
     return re.findall(r'op_name="([^"]*)"', hlo)
@@ -64,6 +72,39 @@ def while_job_op_names():
 def test_while_job_names_stage_scope(while_job_op_names, scope):
     assert any(scope in n.split("/") or f"({scope})" in n
                for n in while_job_op_names), scope
+
+
+@pytest.mark.parametrize("scope", MODEL_SCOPES)
+def test_while_job_names_forecaster_scope(while_job_op_names, scope):
+    """The forecaster's attention, block feed-forward and head, inside the
+    local update (forward and backward) and the eval."""
+    hits = [n for n in while_job_op_names
+            if scope in n.split("/") or f"({scope})" in n]
+    assert any("fl.local_update" in n for n in hits), scope
+    assert any("fl.eval" in n for n in hits), scope
+
+
+def test_scopes_leave_the_job_bitwise_unchanged(monkeypatch):
+    """Scopes are metadata only: a job traced with every ``named_scope``
+    made a no-op gives the same losses, RMSEs and weights, bit for bit."""
+    import contextlib
+
+    model_cfg, fl_cfg, tr, te = _tiny_job()
+
+    def job():
+        jax.clear_caches()
+        h = E.run_fl(model_cfg, fl_cfg, tr, te, jax.random.PRNGKey(3),
+                     max_rounds=4, patience=5, eval_every=2, driver="while")
+        return (np.asarray(h["train_loss"]), np.asarray(h["rmse"]),
+                np.asarray(h["state"]["w_global"]))
+
+    scoped = job()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = job()
+    jax.clear_caches()
+    for a, b in zip(scoped, bare):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_window_gather_and_adam_nest_in_local_update(while_job_op_names):
